@@ -18,6 +18,7 @@ from repro import configs
 from repro.core.quantize import quantize as _quantize_fn
 from repro.core.schemes import QuantScheme
 from repro.dist.sync import gather_stats
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.train.data import DataConfig, Pipeline
 from repro.train.optim import OptimConfig, apply_updates, init_opt_state
@@ -89,7 +90,7 @@ class SimWorkers:
         self.M = M
         cfg = configs.get_config(arch)
         self.cfg = cfg
-        self.mesh = jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = make_mesh((1, 1), ("data", "model"))
         self.model = Model(cfg, tp=1, dp=1)
         self.pipe = Pipeline(DataConfig(
             kind="markov", vocab_size=cfg.vocab_size, seq_len=seq_len,

@@ -1,7 +1,1 @@
-"""Adaptive Gradient Quantization for Data-Parallel SGD — reproduction.
-
-Importing the package installs the jax API backfills (see _jax_compat)
-before any submodule touches jax, so the whole tree runs on the pinned
-jax as well as on current releases.
-"""
-from . import _jax_compat  # noqa: F401  (side effect: API backfills)
+"""Adaptive Gradient Quantization for Data-Parallel SGD — reproduction."""

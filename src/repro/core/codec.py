@@ -143,6 +143,11 @@ def _align_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+# buckets per segment are padded to a multiple of this: part of the
+# wire layout (pinned by the codec goldens), not a kernel constraint
+PLAN_BUCKET_ALIGN = 8
+
+
 # ---------------------------------------------------------------------------
 # protocol
 # ---------------------------------------------------------------------------
@@ -187,8 +192,7 @@ class GradientCodec:
         """Layout for a ``d``-coordinate tensor split into ``shards``
         segments; bucket count is padded to ``shards * tile``."""
         if tile is None:
-            from repro.kernels.quantize import DEFAULT_BUCKET_TILE
-            tile = DEFAULT_BUCKET_TILE
+            tile = PLAN_BUCKET_ALIGN
         nb = _align_up(-(-d // self.bucket_size), shards * tile)
         return self.plan_buckets(nb, shards=shards, d=d)
 
@@ -287,6 +291,25 @@ def _unpack_norm_rows(nwords: jnp.ndarray, nb: int,
         lambda w: packing.unpack_norms(w, nb, norm_dtype))(nwords)
 
 
+# Decoded streams move between (M, n) rows and (M * nb, bucket) buckets
+# one worker's row at a time: reshaping all M rows at once is a relayout
+# the TPU compiler takes time over that grows with n (about 100 s for
+# four rows of 16M coordinates), the per-row reshape takes seconds.
+
+def _streams_to_buckets(words: jnp.ndarray, unpack, nb: int,
+                        bucket: int) -> jnp.ndarray:
+    """(M, words) -> (M * nb, bucket) symbols, ``unpack`` per row."""
+    rows = jax.lax.map(lambda w: unpack(w).reshape(nb, bucket), words)
+    return rows.reshape(-1, bucket)
+
+
+def _buckets_to_streams(vals: jnp.ndarray, M: int) -> jnp.ndarray:
+    """(M * nb, bucket) -> (M, nb * bucket)."""
+    nb, bucket = vals.shape[0] // M, vals.shape[1]
+    return jax.lax.map(lambda x: x.reshape(nb * bucket),
+                       vals.reshape(M, nb, bucket))
+
+
 # ---------------------------------------------------------------------------
 # uniform codec: one global width (the paper's wire format)
 # ---------------------------------------------------------------------------
@@ -376,12 +399,13 @@ class UniformCodec(GradientCodec):
         L = levels.shape[0]
         M = norms.shape[0]
         wb = packing.wire_bits_for(L)
-        usym = jax.vmap(lambda w: packing.unpack(w, n, wb))(words)
-        sym = packing.unbias_codes(usym, L)
+        usym = _streams_to_buckets(
+            words, lambda w: packing.unpack(w, n, wb), snb,
+            self.bucket_size)                          # (M * snb, bucket)
         vals = ops.dequantize_op(
-            sym.reshape(M * snb, self.bucket_size), norms.reshape(-1),
-            levels, use_pallas=use_pallas)
-        vals = vals.reshape(M, n)
+            packing.unbias_codes(usym, L), norms.reshape(-1), levels,
+            use_pallas=use_pallas)
+        vals = _buckets_to_streams(vals, M)
         valid = None
         if want_valid:
             if stored is None:
@@ -665,7 +689,7 @@ class EntropyCodec(UniformCodec):
         vals = ops.dequantize_op(
             packing.unbias_codes(sym.reshape(M * snb, bs), L),
             norms.reshape(-1), levels, use_pallas=use_pallas)
-        vals = vals.reshape(M, snb * bs)
+        vals = _buckets_to_streams(vals, M)
         valid = None
         if want_valid:
             if stored is None:
@@ -985,7 +1009,7 @@ class MixedWidthCodec(GradientCodec):
                 sym.reshape(M * cnt, bs), norms[:, loc].reshape(-1), lv,
                 use_pallas=use_pallas)
             out = out.at[:, loc].set(vals.reshape(M, cnt, bs))
-        return out.reshape(M, snb * bs)
+        return _buckets_to_streams(out.reshape(M * snb, bs), M)
 
     def decode(self, payload, levels, plan, *, shard=None,
                use_pallas=True):

@@ -45,10 +45,14 @@ def pack(codes: jnp.ndarray, bits: int) -> jnp.ndarray:
     spill_shift = jnp.where(off > 0, jnp.uint32(32) - off, jnp.uint32(31))
     hi = jnp.where(off > 0, codes >> spill_shift, jnp.uint32(0))
     # scatter into nwords+1 (spill slot), then drop the spill word — it is
-    # always zero when the stream length is exact.
+    # always zero when the stream length is exact.  The word indices rise
+    # with the symbol index; told so, the TPU compiler takes 2 s instead
+    # of 35 s at 113M symbols when ``bits`` does not divide 32.
     out = jnp.zeros((nwords + 1,), jnp.uint32)
-    out = out.at[widx].add(lo, mode="promise_in_bounds")
-    out = out.at[widx + 1].add(hi, mode="promise_in_bounds")
+    out = out.at[widx].add(lo, mode="promise_in_bounds",
+                           indices_are_sorted=True)
+    out = out.at[widx + 1].add(hi, mode="promise_in_bounds",
+                               indices_are_sorted=True)
     return out[:nwords]
 
 

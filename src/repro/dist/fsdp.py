@@ -195,39 +195,24 @@ def _float0_zeros(x):
     return np.zeros(jnp.shape(x), jax.dtypes.float0)
 
 
-def _check_not_vmapped(shard, axes):
-    """Fail fast on the known jax-0.4.37 quirk: batching the gather's
-    ``custom_vjp`` backward (an ``all_to_all`` reduce-scatter) under a
-    PLAIN ``jax.vmap`` axis mis-shapes the collective's batching rule
+def _check_in_shard_map(axes):
+    """Fail fast on a jax quirk: batching the gather's ``custom_vjp``
+    backward (an ``all_to_all`` reduce-scatter) under a PLAIN
+    ``jax.vmap`` axis mis-shapes the collective's batching rule
     (``mul got incompatible shapes for broadcasting``).  The shard_map
     path is unaffected — and is the production path — so point there
-    instead of letting the broadcast error surface layers deeper.
+    instead of letting the broadcast error surface layers deeper.  The
+    gather's axes are manual mesh axes only inside ``shard_map``.
     """
-    from jax.interpreters import batching
-    x = shard
-    batched = False
-    while isinstance(x, jax.core.Tracer):
-        if isinstance(x, batching.BatchTracer):
-            batched = True
-            break
-        # unwrap one autodiff/batching level (grad wraps the vmap
-        # tracer in a JVPTracer, so one isinstance is not enough)
-        if hasattr(x, "primal"):
-            x = x.primal
-        elif hasattr(x, "val"):
-            x = x.val
-        else:
-            break
-    if batched:
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    if not set(axes) <= set(manual):
         raise NotImplementedError(
-            "make_gather cannot run under a plain jax.vmap axis on this "
-            "jax pin (0.4.37): vmap-batching the custom_vjp backward's "
-            "all_to_all reduce-scatter hits a known custom_vjp x "
-            "all_to_all batching quirk.  Run the gather inside "
-            "jax.shard_map over mesh axes "
-            f"{tuple(axes)!r} instead (see tests/test_fsdp_quantized.py "
-            "for the harness), or call _quantized_reduce_scatter "
-            "directly — plain functions vmap fine.")
+            "make_gather must run inside jax.shard_map over mesh axes "
+            f"{tuple(axes)!r} (see tests/test_fsdp_quantized.py for the "
+            "harness): under a plain jax.vmap axis, batching the "
+            "custom_vjp backward's all_to_all reduce-scatter hits a "
+            "custom_vjp x all_to_all batching quirk.  Plain functions "
+            "such as _quantized_reduce_scatter vmap fine.")
 
 
 def make_gather(data_axes, scheme: QuantScheme, fsdp_sync: str = "quantized",
@@ -260,7 +245,7 @@ def make_gather(data_axes, scheme: QuantScheme, fsdp_sync: str = "quantized",
     ``use_pallas`` defaults to False: on CPU the interpret-mode kernels
     materialize every grid block (see launch/dryrun.py); flip it on for
     real-TPU runs.  ``guard_vmap=False`` disables the fail-fast check
-    for the known plain-vmap batching quirk (kept only so the pinning
+    for the plain-vmap batching quirk (kept only so the pinning
     xfail test can exercise the raw behavior).
     """
     axes = tuple(data_axes)
@@ -280,7 +265,7 @@ def make_gather(data_axes, scheme: QuantScheme, fsdp_sync: str = "quantized",
 
     def gather(shard, levels, key):
         if guard_vmap:
-            _check_not_vmapped(shard, axes)
+            _check_in_shard_map(axes)
 
         @jax.custom_vjp
         def f(s, lv, k):
@@ -306,7 +291,7 @@ def make_gather(data_axes, scheme: QuantScheme, fsdp_sync: str = "quantized",
 
     def gather_ef(shard, levels, key, residual):
         if guard_vmap:
-            _check_not_vmapped(shard, axes)
+            _check_in_shard_map(axes)
 
         @jax.custom_vjp
         def f(s, lv, k, r):

@@ -200,7 +200,11 @@ def _allreduce_two_phase(flat, codec, levels, key, transport, use_pallas):
         corrupt = corrupt / denom
     else:
         out = codec2.decode(g2, lv2, plan2, use_pallas=use_pallas)
-    out = out.reshape(-1)[:d]
+    # the M shards joined into one materialized stream: a consumer that
+    # reshapes slices of it (the trainer's unravel into parameters)
+    # would otherwise fuse into one relayout of the (M, n) rows per
+    # slice, which took the TPU compiler minutes at 170M coordinates
+    out = jax.lax.optimization_barrier(out.reshape(-1))[:d]
 
     # own phase-1 payload, decoded shard by shard, for the error metric
     # (and for the compress layer's residual feedback)

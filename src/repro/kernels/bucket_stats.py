@@ -5,6 +5,8 @@ the first two moments of the normalized magnitudes — exactly what
 ``repro.core.stats.fit_bucket_stats`` needs to fit the truncated-normal
 mixture.  Fusing avoids a second HBM sweep over the gradient (the
 adaptive methods' extra cost is this kernel once every ~10k steps).
+Same bucket-tile layout as quantize.py; each statistic leaves as a
+(tile, 1) block.
 """
 from __future__ import annotations
 
@@ -14,59 +16,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.quantize import NORM_L2, NORM_LINF
-from .quantize import DEFAULT_BUCKET_TILE
+from repro.core.quantize import NORM_L2
+from .quantize import bucket_norms, bucket_tile, pallas_per_platform
 
 
 def _bucket_stats_kernel(v_ref, norms_ref, mu_ref, var_ref, *, norm_type: str):
     v = v_ref[...].astype(jnp.float32)
-    if norm_type == NORM_L2:
-        norm = jnp.sqrt(jnp.sum(v * v, axis=-1))
-    elif norm_type == NORM_LINF:
-        norm = jnp.max(jnp.abs(v), axis=-1)
-    else:
-        raise ValueError(norm_type)
+    norm = bucket_norms(v, norm_type)
     safe = jnp.where(norm > 0, norm, 1.0)
-    r = jnp.abs(v) / safe[:, None]
-    mu = jnp.mean(r, axis=-1)
-    var = jnp.mean(r * r, axis=-1) - mu * mu
+    r = jnp.abs(v) / safe
+    mu = jnp.mean(r, axis=-1, keepdims=True)
+    var = jnp.mean(r * r, axis=-1, keepdims=True) - mu * mu
     norms_ref[...] = norm
     mu_ref[...] = mu
     var_ref[...] = jnp.maximum(var, 0.0)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("norm_type", "bucket_tile", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("norm_type",))
 def bucket_stats_pallas(
     vb: jnp.ndarray,
     *,
     norm_type: str = NORM_L2,
-    bucket_tile: int = DEFAULT_BUCKET_TILE,
-    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Returns per-bucket (norms, mean_r, var_r), each (num_buckets,)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     nb, bs = vb.shape
-    bucket_tile = min(bucket_tile, nb)
-    if nb % bucket_tile:
-        raise ValueError(f"num_buckets {nb} % bucket_tile {bucket_tile} != 0")
-    grid = (nb // bucket_tile,)
+    tile = bucket_tile(nb, bs)
     kernel = functools.partial(_bucket_stats_kernel, norm_type=norm_type)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((bucket_tile, bs), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((bucket_tile,), lambda i: (i,)),
-            pl.BlockSpec((bucket_tile,), lambda i: (i,)),
-            pl.BlockSpec((bucket_tile,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
-            jax.ShapeDtypeStruct((nb,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(vb)
+    stat = pl.BlockSpec((tile, 1), lambda i: (i, 0))
+
+    def call(vb, *, interpret):
+        return pl.pallas_call(
+            kernel,
+            grid=(pl.cdiv(nb, tile),),
+            in_specs=[pl.BlockSpec((tile, bs), lambda i: (i, 0))],
+            out_specs=[stat, stat, stat],
+            out_shape=[jax.ShapeDtypeStruct((nb, 1), jnp.float32)] * 3,
+            name="bucket_stats",
+            interpret=interpret,
+        )(vb)
+
+    return tuple(s[:, 0] for s in pallas_per_platform(call, vb))

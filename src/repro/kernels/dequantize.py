@@ -1,8 +1,9 @@
 """Pallas TPU kernel: decode (DECODE_l, Algorithm 1 line 8).
 
 codes (int8 signed level indices) + per-bucket norms -> f32 values.
-Same bucket-tile layout as quantize.py.  The level lookup is a one-hot
-contraction (VPU) rather than a gather — TPU-native for tiny tables.
+Same bucket-tile layout as quantize.py: norms arrive as a (tile, 1)
+block.  The level lookup is a short loop of selects over the levels in
+SMEM rather than a gather — TPU-native for tiny tables.
 """
 from __future__ import annotations
 
@@ -11,49 +12,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .quantize import DEFAULT_BUCKET_TILE
+from .quantize import bucket_tile, pallas_per_platform
 
 
 def _dequantize_kernel(codes_ref, norms_ref, levels_ref, out_ref):
     codes = codes_ref[...].astype(jnp.int32)
-    norms = norms_ref[...]
-    levels = levels_ref[...]
-
     idx = jnp.abs(codes)
-    nlev = levels.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, idx.shape + (nlev,), idx.ndim)
-    onehot = (iota == idx[..., None]).astype(jnp.float32)
-    mags = jnp.sum(onehot * levels[None, None, :], axis=-1)
-    sign = jnp.sign(codes).astype(jnp.float32)
-    out_ref[...] = mags * sign * norms[:, None]
+
+    def step(j, mags):
+        return jnp.where(idx == j, levels_ref[j], mags)
+
+    mags = jax.lax.fori_loop(
+        1, levels_ref.shape[0], step,
+        jnp.full(idx.shape, levels_ref[0], jnp.float32))
+    sign = jnp.where(codes > 0, 1.0, jnp.where(codes < 0, -1.0, 0.0))
+    out_ref[...] = mags * sign * norms_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("bucket_tile", "interpret"))
+@jax.jit
 def dequantize_pallas(
     codes: jnp.ndarray,
     norms: jnp.ndarray,
     levels: jnp.ndarray,
-    *,
-    bucket_tile: int = DEFAULT_BUCKET_TILE,
-    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    """(num_buckets, bucket_size) codes + (num_buckets,) norms -> f32."""
     nb, bs = codes.shape
-    bucket_tile = min(bucket_tile, nb)
-    if nb % bucket_tile:
-        raise ValueError(f"num_buckets {nb} % bucket_tile {bucket_tile} != 0")
-    grid = (nb // bucket_tile,)
-    return pl.pallas_call(
-        _dequantize_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bucket_tile, bs), lambda i: (i, 0)),
-            pl.BlockSpec((bucket_tile,), lambda i: (i,)),
-            pl.BlockSpec(levels.shape, lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bucket_tile, bs), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, bs), jnp.float32),
-        interpret=interpret,
-    )(codes, norms, levels)
+    tile = bucket_tile(nb, bs)
+
+    def call(codes, norms, levels, *, interpret):
+        return pl.pallas_call(
+            _dequantize_kernel,
+            grid=(pl.cdiv(nb, tile),),
+            in_specs=[
+                pl.BlockSpec((tile, bs), lambda i: (i, 0)),
+                pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
+            out_specs=pl.BlockSpec((tile, bs), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((nb, bs), jnp.float32),
+            name="dequantize",
+            interpret=interpret,
+        )(codes, norms, levels)
+
+    return pallas_per_platform(
+        call, codes, norms.astype(jnp.float32).reshape(nb, 1),
+        levels.astype(jnp.float32))

@@ -1,32 +1,21 @@
-"""Public jit'd entry points for the Pallas kernels.
+"""Public entry points for the Pallas kernels.
 
-``use_pallas`` switches between the Pallas path (interpret-mode on CPU,
-compiled on TPU) and the pure-jnp oracle — the distributed sync layer
-calls through here so the whole framework runs on either.
-
-The entry points also pick a *valid* bucket tile for the kernels: the
-Pallas grid requires ``num_buckets % bucket_tile == 0``, and the sync /
-FSDP layers produce bucket counts that are bucket- and shard-aligned but
-not always tile-aligned (e.g. a reduce-scatter round of M*ppr buckets).
+``use_pallas`` switches between the Pallas path (interpret-mode when
+lowered for the CPU, compiled by Mosaic when lowered for a TPU) and the
+pure-jnp oracle — the distributed sync layer calls through here so the
+whole framework runs on either.  The kernels take any bucket count: the
+tile choice and the ragged last grid block live with the kernels
+(``quantize.bucket_tile``).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.quantize import NORM_L2
 from . import ref
 from .bucket_stats import bucket_stats_pallas
 from .dequantize import dequantize_pallas
-from .quantize import DEFAULT_BUCKET_TILE, quantize_pallas
-
-
-def _tile_for(num_buckets: int) -> int:
-    """Largest tile <= DEFAULT_BUCKET_TILE that divides num_buckets."""
-    t = min(DEFAULT_BUCKET_TILE, num_buckets)
-    while num_buckets % t:
-        t -= 1
-    return t
+from .quantize import quantize_pallas
 
 
 def quantize_op(
@@ -38,8 +27,7 @@ def quantize_op(
     use_pallas: bool = True,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     if use_pallas:
-        return quantize_pallas(vb, u, levels, norm_type=norm_type,
-                               bucket_tile=_tile_for(vb.shape[0]))
+        return quantize_pallas(vb, u, levels, norm_type=norm_type)
     return ref.quantize_ref(vb, u, levels, norm_type)
 
 
@@ -51,8 +39,7 @@ def dequantize_op(
     use_pallas: bool = True,
 ) -> jnp.ndarray:
     if use_pallas:
-        return dequantize_pallas(codes, norms, levels,
-                                 bucket_tile=_tile_for(codes.shape[0]))
+        return dequantize_pallas(codes, norms, levels)
     return ref.dequantize_ref(codes, norms, levels)
 
 
@@ -60,6 +47,5 @@ def bucket_stats_op(
     vb: jnp.ndarray, *, norm_type: str = NORM_L2, use_pallas: bool = True
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     if use_pallas:
-        return bucket_stats_pallas(vb, norm_type=norm_type,
-                                   bucket_tile=_tile_for(vb.shape[0]))
+        return bucket_stats_pallas(vb, norm_type=norm_type)
     return ref.bucket_stats_ref(vb, norm_type)

@@ -20,13 +20,19 @@ def _norms(vb: jnp.ndarray, norm_type: str) -> jnp.ndarray:
 
 
 def quantize_ref(
-    vb: jnp.ndarray, u: jnp.ndarray, levels: jnp.ndarray, norm_type: str
+    vb: jnp.ndarray, u: jnp.ndarray, levels: jnp.ndarray, norm_type: str,
+    norms: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused bucket-norm + normalize + stochastic round.
 
-    Returns (codes int8 signed level indices, norms f32).
+    Returns (codes int8 signed level indices, norms f32).  Given
+    ``norms``, rounds against them instead of computing its own: two
+    compilers may sum a bucket's squares in different orders, and a
+    last-bit change of the norm can move a coordinate across a rounding
+    threshold.
     """
-    norms = _norms(vb, norm_type)
+    if norms is None:
+        norms = _norms(vb, norm_type)
     safe = jnp.where(norms > 0, norms, 1.0)
     r = jnp.clip(jnp.abs(vb.astype(jnp.float32)) / safe[:, None], 0.0, 1.0)
     # tau = #levels <= r, minus one (levels sorted, levels[0]=0 so tau>=0);

@@ -27,7 +27,8 @@ from repro import configs
 from repro.configs.shapes import SHAPES, input_specs
 from repro.core.schemes import QuantScheme
 from repro.launch import hlo_analysis, jaxpr_cost
-from repro.launch.mesh import make_production_mesh, mesh_axes
+from repro.launch.mesh import (
+    PRODUCTION_DEVICE_KIND, make_production_mesh, mesh_axes)
 from repro.models.transformer import Model
 from repro.train.optim import OptimConfig
 from repro.train.train_step import (
@@ -205,7 +206,7 @@ def run_one(arch, shape_name, mesh_kind, *, sync_mode, out_dir,
             scheme_name=scheme_name, bits=bits,
             microbatches=microbatches, remat=remat)
         mem = compiled.memory_analysis()
-        hlo_roof = hlo_analysis.analyze(compiled)
+        hlo_roof = hlo_analysis.analyze(compiled, PRODUCTION_DEVICE_KIND)
         # primary roofline terms from the jaxpr walker (scan-exact);
         # compiled cost_analysis kept as a secondary record
         roof = hlo_analysis.Roofline(
@@ -213,6 +214,7 @@ def run_one(arch, shape_name, mesh_kind, *, sync_mode, out_dir,
             hbm_bytes_per_device=acost.hbm_bytes,
             collective_wire_bytes=acost.collective_bytes,
             bytes_by_kind=acost.by_collective,
+            peaks=hlo_roof.peaks,
         )
         # model flops: 6*N_active*D for training, 2*N_active*D prefill,
         # 2*N_active*B decode

@@ -5,17 +5,36 @@ text and sum the result sizes of every collective op, weighted by its
 wire pattern (all-reduce moves ~2x its payload on a ring; reduce-scatter
 and all-gather ~1x; all-to-all and collective-permute 1x).
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (constants from the assignment).
+Hardware peaks are keyed by ``device_kind`` (``PEAKS``); a kind with no
+entry is an error, not a default.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float      # bf16 FLOP/s per chip
+    hbm_bw: float     # HBM bytes/s per chip
+    ici_bw: float     # interconnect bytes/s per link
+
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    # HBM, 1,600 Gbit/s of interconnect per chip over 4 links
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            f"them to hlo_analysis.PEAKS with their source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -85,18 +104,19 @@ class Roofline:
     hbm_bytes_per_device: float
     collective_wire_bytes: float
     bytes_by_kind: dict
+    peaks: Peaks
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes_per_device / HBM_BW
+        return self.hbm_bytes_per_device / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_wire_bytes / ICI_BW
+        return self.collective_wire_bytes / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -117,7 +137,8 @@ class Roofline:
         }
 
 
-def analyze(compiled) -> Roofline:
+def analyze(compiled, device_kind: str) -> Roofline:
+    """Roofline terms of ``compiled`` on a chip of ``device_kind``."""
     cost = compiled.cost_analysis()
     cb = collective_bytes(compiled.as_text())
     return Roofline(
@@ -125,4 +146,5 @@ def analyze(compiled) -> Roofline:
         hbm_bytes_per_device=float(cost.get("bytes accessed", 0.0)),
         collective_wire_bytes=float(cb["total_wire"]),
         bytes_by_kind=cb,
+        peaks=peaks_for(device_kind),
     )

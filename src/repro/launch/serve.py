@@ -15,6 +15,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, mesh_axes
 from repro.models.transformer import Model
 from repro.serve.engine import ServeConfig, make_decode_step, make_prefill_step
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--tp", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))

@@ -27,7 +27,8 @@ def main(argv=None) -> int:
                     help="override the cluster's worker count")
     ap.add_argument("--out", default=None,
                     help="output path (default: SIM_<scenario>.json)")
-    ap.add_argument("--use-pallas", action="store_true",
+    ap.add_argument("--use-pallas", action=argparse.BooleanOptionalAction,
+                    default=False,
                     help="run the Pallas kernel path (interpret mode on "
                          "CPU; slower, kernel-faithful)")
     ap.add_argument("--list", action="store_true",

@@ -44,6 +44,7 @@ from repro.core.schemes import QuantScheme, SchemeState
 from repro.core.stats import expected_variance
 from repro.dist.faults import FaultModel
 from repro.dist.sync import gather_stats
+from repro.launch.mesh import make_mesh
 from repro.models import Model
 from repro.train.data import DataConfig, Pipeline
 from repro.train.optim import OptimConfig, OptState, apply_updates, init_opt_state
@@ -507,7 +508,7 @@ def _run_cell(scn: Scenario, spec: str, topo: str, comp_spec: str,
     scheme = scn.make_scheme(spec)
     cfg = configs.get_config(scn.arch)
     M = scn.cluster.num_workers
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = Model(cfg, tp=1, dp=1)
     pipe = Pipeline(DataConfig(
         kind="markov", vocab_size=cfg.vocab_size, seq_len=scn.seq_len,

@@ -5,7 +5,9 @@ Two sources, both deterministic and host-side (the container is offline):
   * ``markov``: sequences sampled from a fixed random bigram table — a
     *learnable* synthetic LM task, so integration tests and examples can
     assert the loss actually decreases;
-  * ``uniform``: i.i.d. uniform tokens (throughput/dry-run filler).
+  * ``uniform``: i.i.d. uniform tokens (throughput/dry-run filler) —
+    the source for published vocabularies, whose bigram table would not
+    fit in host memory (vocab^2 float64: 185 GB at V = 151,936).
 
 Batches are yielded as already-global arrays; the launcher shards them
 over the DP mesh axes with ``jax.device_put`` + NamedSharding.
@@ -17,6 +19,11 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# largest vocabulary whose vocab x vocab float64 bigram table the
+# markov source builds (134 MB)
+MARKOV_MAX_VOCAB = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +41,12 @@ class Pipeline:
         self.cfg = cfg
         rng = np.random.default_rng(cfg.seed)
         if cfg.kind == "markov":
+            if cfg.vocab_size > MARKOV_MAX_VOCAB:
+                raise ValueError(
+                    f"the markov source builds a vocab x vocab bigram "
+                    f"table; vocab {cfg.vocab_size} > {MARKOV_MAX_VOCAB} "
+                    f"would take {cfg.vocab_size ** 2 * 8 / 1e9:.0f} GB "
+                    f"of host memory — use kind='uniform'")
             logits = rng.standard_normal((cfg.vocab_size, cfg.vocab_size))
             logits /= cfg.markov_temperature
             p = np.exp(logits - logits.max(-1, keepdims=True))

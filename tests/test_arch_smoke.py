@@ -20,7 +20,8 @@ ARCHS = list(configs.ARCH_NAMES)
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -247,7 +247,8 @@ gather = fsdp.make_gather(("w",), scheme, "quantized",
                           use_pallas=False, codec=codec)
 lv = scheme.init_state().levels
 key = jax.random.PRNGKey(11)
-mesh = jax.make_mesh((4,), ("w",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("w",))
 shards = jax.random.normal(jax.random.PRNGKey(5), (Lp,))
 target = np.asarray(
     jax.random.normal(jax.random.PRNGKey(6), (Lp,))) * 0.01

@@ -15,7 +15,7 @@
   contracts; the 4-arg make_gather threads the residual through the
   custom_vjp under real shard_map;
 * make_gather under a PLAIN vmap axis fails fast with an actionable
-  error (and the underlying jax-0.4.37 quirk stays pinned by an xfail);
+  error (and the underlying jax quirk stays pinned by an xfail);
 * CompressState checkpoints: save -> restore -> bit-identical next step
   with 'ef' enabled;
 * mixed-width re-assignment follows a synthetic bucket-stats shift;
@@ -397,7 +397,8 @@ gather = fsdp.make_gather(("w",), scheme, "quantized",
                           use_pallas=False, algorithm=algo)
 lv = scheme.init_state().levels
 key = jax.random.PRNGKey(11)
-mesh = jax.make_mesh((4,), ("w",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("w",))
 shards = jax.random.normal(jax.random.PRNGKey(5), (Lp,))
 target = jnp.asarray(
     np.asarray(jax.random.normal(jax.random.PRNGKey(6), (Lp,))) * 0.01)
@@ -419,13 +420,16 @@ assert ds.shape == (Lp,) and new_r.shape == (M, Lp)
 
 # reference: the plain (non-custom_vjp) function under vmap with the
 # same cotangent: the gathered full vector IS `shards`, so the loss
-# cotangent w.r.t. it is 2*(shards - target) on every worker
+# cotangent w.r.t. it is 2*(shards - target) on every worker.  Jitted
+# like the shard_map side: XLA's CPU backend may fuse the decode's
+# multiply into the residual subtraction, which differs from op-by-op
+# eager execution in the last bit
 cotangent = 2.0 * (shards - target)
-rs_ref, new_r_ref = jax.vmap(
+rs_ref, new_r_ref = jax.jit(jax.vmap(
     lambda r: fsdp._quantized_reduce_scatter(
         cotangent, lv, key, axes=("w",), codec=algo.codec,
         use_pallas=False, residual=r),
-    axis_name="w")(r0)
+    axis_name="w"))(r0)
 np.testing.assert_array_equal(np.asarray(new_r), np.asarray(new_r_ref))
 np.testing.assert_array_equal(
     np.asarray(ds), np.asarray(rs_ref).reshape(-1))
@@ -463,8 +467,9 @@ def test_make_gather_under_plain_vmap_raises_actionable():
 
 
 @pytest.mark.xfail(strict=True, raises=Exception,
-                   reason="jax-0.4.37 custom_vjp x all_to_all batching "
-                          "quirk: vmap's batching rule mis-shapes the "
+                   reason="jax custom_vjp x all_to_all batching quirk "
+                          "(still present in jax 0.9.0): vmap's batching "
+                          "rule mis-shapes the "
                           "backward's collective (pinned; if this "
                           "XPASSes after a jax upgrade, the guard in "
                           "make_gather can be retired)")
@@ -487,7 +492,8 @@ def _train_harness(compress, steps, state=None, seed=0):
         make_train_step, metric_specs)
 
     cfg = configs.get_config("paper-proxy")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = Model(cfg, tp=1, dp=1)
     tcfg = TrainConfig(
         scheme=QuantScheme(name="qsgdinf", bits=2, bucket_size=1024),

@@ -32,7 +32,8 @@ CONFIGS = [
 @pytest.mark.parametrize("cfg", CONFIGS, ids=lambda c: c.name)
 def test_prefill_decode_matches_forward(cfg):
     S, n_decode, max_len = 24, 3, 64
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     m = Model(cfg, tp=1, dp=1)
     params = m.init(jax.random.PRNGKey(0))
     B = 2

@@ -34,7 +34,8 @@ from jax.sharding import PartitionSpec as P
 from repro.dist.sync import quantized_allreduce
 from repro.core.schemes import QuantScheme
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("pod", "data"))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 2048)) * 0.01
 fp = g.mean(0)
 scheme = QuantScheme(name="alq", bits=4, bucket_size=256)
@@ -67,7 +68,8 @@ from repro.core.schemes import QuantScheme
 cfg = ModelConfig(name="t", arch_type="dense", num_layers=2, d_model=64,
                   num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=256,
                   compute_dtype="float32")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 ids = jax.random.randint(jax.random.PRNGKey(0), (8, 16), 0, 256)
 labels = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 256)
 
@@ -115,7 +117,8 @@ from repro import configs
 from repro.configs.shapes import InputShape
 from repro.launch import dryrun
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = configs.get_smoke_config("llama3.2-1b")
 for shape in (InputShape("t", 64, 8, "train"),
               InputShape("d", 128, 8, "decode")):

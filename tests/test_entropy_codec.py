@@ -185,7 +185,8 @@ from repro.dist import fsdp, sync
 M, D = 8, 4096
 scheme = QuantScheme(name="alq", bits=3, bucket_size=256)
 state = scheme.init_state()
-mesh = jax.make_mesh((M,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((M,), ("data",))
 g = jax.random.normal(jax.random.PRNGKey(0), (M, D)) * 0.01
 key = jax.random.PRNGKey(7)
 uc = codec_for_scheme(scheme)

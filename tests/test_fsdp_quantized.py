@@ -17,7 +17,8 @@ from repro.core.schemes import QuantScheme
 cfg = ModelConfig(name="t", arch_type="dense", num_layers=2, d_model=64,
                   num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=256,
                   compute_dtype="float32")
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 ids = jax.random.randint(jax.random.PRNGKey(0), (8, 16), 0, 256)
 labels = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, 256)
 scheme = QuantScheme(name="alq", bits=8, bucket_size=256)

@@ -1,7 +1,7 @@
 """Pallas kernel validation: sweep shapes/dtypes/norms/grids and assert
 allclose (codes: exact) against the pure-jnp oracles in kernels/ref.py.
-Kernels run in interpret=True on CPU (the TPU lowering is exercised by
-pl.pallas_call's BlockSpec machinery either way)."""
+Kernels run in the Pallas interpreter on CPU; tests/test_tpu_compile.py
+compiles the same kernels for a TPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -72,3 +72,29 @@ def test_encode_decode_roundtrip_preserves_unbiasedness():
     qs = jax.lax.map(qdq, keys)
     err = jnp.abs(qs.mean(0) - v).max() / jnp.abs(v).std()
     assert float(err) < 0.5
+
+
+@pytest.mark.parametrize("nb", [40, 72])
+def test_kernels_ragged_last_block_match_oracle(nb):
+    """At bucket 4096 the grid steps over 32 buckets, which do not
+    divide ``nb``: the last grid block is ragged, and its rows past
+    num_buckets must not disturb the result."""
+    from repro.kernels import (bucket_stats_pallas, dequantize_pallas,
+                               quantize_pallas)
+    from repro.kernels.quantize import bucket_tile
+    bs = 4096
+    assert nb % bucket_tile(nb, bs) != 0
+    levels = uniform_levels(3)
+    v = jax.random.normal(jax.random.PRNGKey(nb), (nb, bs)) * 0.1
+    u = jax.random.uniform(jax.random.PRNGKey(8), (nb, bs), jnp.float32)
+    c1, n1 = quantize_pallas(v, u, levels)
+    c2, n2 = ref.quantize_ref(v, u, levels, "l2")
+    assert jnp.all(c1 == c2)
+    np.testing.assert_allclose(np.asarray(n1), np.asarray(n2), rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(dequantize_pallas(c2, n2, levels)),
+        np.asarray(ref.dequantize_ref(c2, n2, levels)), rtol=1e-6)
+    for a, b in zip(bucket_stats_pallas(v),
+                    ref.bucket_stats_ref(v, "l2")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)

@@ -22,7 +22,8 @@ cfg = ModelConfig(name="t", arch_type="dense", num_layers=2, d_model=64,
 S, n_dec, max_len = 24, 3, 32
 ids = jax.random.randint(jax.random.PRNGKey(1), (1, S + n_dec), 0, 256)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 # batch-1 long-context layout: cache sequence sharded over data AND model
 m = Model(cfg, tp=2, dp=4, data_axes=("data",),
           seq_shard_axes=("data", "model"))

@@ -60,7 +60,8 @@ def test_jaxpr_cost_multiplies_scan_trip_count():
 
 
 def test_jaxpr_cost_counts_collectives_inside_scan():
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
 
     def f(xs):
@@ -126,3 +127,16 @@ def test_mesh_helpers():
     data_axes, model_axis = mesh_axes(mesh)
     assert model_axis == "model"
     assert data_axes == ("data",)
+    from jax.sharding import AxisType
+    assert set(mesh.axis_types) == {AxisType.Auto}
+
+
+def test_roofline_peaks_keyed_on_device_kind():
+    from repro.launch import hlo_analysis
+    from repro.launch.mesh import PRODUCTION_DEVICE_KIND
+    peaks = hlo_analysis.peaks_for(PRODUCTION_DEVICE_KIND)
+    roof = hlo_analysis.Roofline(197e12, 819e9, 50e9, {}, peaks)
+    assert (roof.compute_s, roof.memory_s, roof.collective_s) == (
+        1.0, 1.0, 1.0)
+    with pytest.raises(ValueError, match="no published peaks"):
+        hlo_analysis.peaks_for("cpu")

@@ -19,7 +19,8 @@ from repro.train.train_step import (
 def run_training(scheme_name, bits, steps=30, sync_mode="all_gather",
                  seed=0, lr=0.3):
     cfg = configs.get_config("paper-proxy")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     model = Model(cfg, tp=1, dp=1)
     tcfg = TrainConfig(
         scheme=QuantScheme(name=scheme_name, bits=bits, bucket_size=1024),
@@ -85,3 +86,35 @@ def test_two_phase_trains():
 def test_baselines_train(scheme):
     losses, _, _ = run_training(scheme, bits=3, steps=12)
     assert all(np.isfinite(losses))
+
+
+def test_launcher_run_returns_step_metrics():
+    """launch/train.run drives the CLI's loop in-process and returns one
+    metrics dict and one wall time per step."""
+    from repro.launch import train
+    args = train.parse_args(["--arch", "paper-proxy", "--steps", "3",
+                             "--batch", "4", "--seq", "32",
+                             "--update-at", "1", "--no-use-pallas"])
+    res = train.run(args)
+    assert len(res.metrics) == len(res.step_s) == 3
+    assert all(np.isfinite(m["loss"]) for m in res.metrics)
+    assert res.compile_s > 0
+    levels0 = res.trainer.tcfg.scheme.init_state().levels
+    assert not np.array_equal(np.asarray(levels0),
+                              np.asarray(res.state.scheme_state.levels))
+
+
+def test_launcher_kernel_flag_defaults_on():
+    from repro.launch import train
+    assert train.parse_args([]).use_pallas
+    assert not train.parse_args(["--no-use-pallas"]).use_pallas
+
+
+def test_markov_source_refuses_published_vocabulary():
+    V = configs.get_config("qwen3-0.6b").vocab_size
+    with pytest.raises(ValueError, match="uniform"):
+        Pipeline(DataConfig(kind="markov", vocab_size=V))
+    b = Pipeline(DataConfig(kind="uniform", vocab_size=V, seq_len=8,
+                            global_batch=2)).batch(0)
+    assert b["ids"].shape == (2, 8)
+    assert int(b["ids"].max()) < V

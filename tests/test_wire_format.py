@@ -60,7 +60,8 @@ scheme = QuantScheme(name="alq", bits=3, bucket_size=256)
 state = scheme.init_state()
 M = 8
 d = 2048  # per-worker length; 8 buckets per worker
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("pod", "data"))
 g = jax.random.normal(jax.random.PRNGKey(0), (M, d)) * 0.01
 key = jax.random.PRNGKey(7)
 
@@ -74,7 +75,7 @@ packed_out = np.asarray(smf(g))
 
 # unpacked reference: same encode (same folded keys/uniforms), but the
 # codes are decoded directly — no pack/all_gather/unpack in the loop
-vals = []
+codes_all, norms_all = [], []
 for r in range(M):
     vb = g[r].reshape(-1, scheme.bucket_size)
     u = jax.random.uniform(jax.random.fold_in(key, r), vb.shape, jnp.float32)
@@ -85,8 +86,13 @@ for r in range(M):
     back = packing.unpack_signed(w, codes.size, scheme.num_levels)
     assert (np.asarray(back).reshape(codes.shape)
             == np.asarray(codes, np.int32)).all()
-    vals.append(ops.dequantize_op(codes, norms, state.levels).reshape(-1))
-ref = np.asarray(jnp.stack(vals).mean(0))
+    codes_all.append(codes)
+    norms_all.append(norms)
+# each worker decoded to its own row, then the mean over the stacked
+# rows, as the collective's decode hands them to the mean
+per_worker = jnp.stack([ops.dequantize_op(c, n, state.levels).reshape(-1)
+                        for c, n in zip(codes_all, norms_all)])
+ref = np.asarray(jax.jit(lambda x: x.mean(0))(per_worker))
 assert (packed_out == ref).all(), np.abs(packed_out - ref).max()
 print("WIRE_OK")
 """
