@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+100 * (1 - busy / window), busy the union of the device's operation
+intervals (``tracereduce.summarize``), averaged over the chips."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])
