@@ -39,6 +39,12 @@ one fused ``bucket_stats`` sweep, strided subsampling to
 ``max_stat_components``, and a tiny cross-worker mixture merge.
 ``maybe_update_levels`` wraps it in ``lax.cond`` so the ~10k non-update
 steps pay nothing.
+
+Every wire mode opens the train step's layer scopes
+(``train_step.LAYER_SCOPES``): ``encode``, ``collective``, ``decode``,
+``step_metrics`` around its counters, and ``level_update`` around the
+level update.  They name the compiled instructions for a profile and
+add no operation.
 """
 from __future__ import annotations
 
@@ -111,39 +117,44 @@ class SyncMetrics(NamedTuple):
 def _allreduce_all_gather(flat, codec, levels, key, transport, use_pallas):
     d = flat.shape[0]
     plan = codec.plan(d)
-    vb = codec.bucketize(flat, plan)
-    payload = codec.encode(vb, levels, key, plan, use_pallas=use_pallas)
+    with jax.named_scope("encode"):
+        vb = codec.bucketize(flat, plan)
+        payload = codec.encode(vb, levels, key, plan, use_pallas=use_pallas)
 
-    gathered = jax.tree.map(transport.all_gather, payload)   # (M, ...)
-    if plan.integrity:
-        # checked decode: per-(worker, bucket) validity verdicts, with
-        # detected-corrupt buckets excluded from the aggregate by the
-        # per-bucket renormalization rule (a fully-invalid worker
-        # aggregates bit-exactly like a transport-masked one).  ``own``
-        # comes from the LOCAL payload, not the gathered row — wire
-        # corruption of one's own row must not poison the error-
-        # feedback residual (bit-identical when the wire is clean).
-        per_worker, valid = codec.decode_checked(gathered, levels, plan,
-                                                 use_pallas=use_pallas)
-        out = transport.mean_workers_bucketed(
-            per_worker, valid, plan.bucket_size)[:d]
-        own = codec.decode(payload, levels, plan,
-                           use_pallas=use_pallas)[:d]
-        corrupt = jnp.mean(1.0 - valid.astype(jnp.float32))
-        excluded = jnp.sum(jnp.all(~valid, axis=1).astype(jnp.float32))
-    else:
-        per_worker = codec.decode(gathered, levels, plan,
-                                  use_pallas=use_pallas)      # (M, n)
-        out = transport.mean_workers(per_worker)[:d]
-        own = jnp.take(per_worker, transport.rank(), axis=0)[:d]
-        corrupt = jnp.float32(0.0)
-        excluded = jnp.float32(0.0)
-    qerr = jnp.sum((own - flat) ** 2)
-    # the single gather IS the broadcast-all hop (paper Sec. 5);
-    # variable-volume codecs report what their headers say this
-    # worker's payload actually ships, not the static capacity
-    bits = (codec.measured_bits_per_coord(payload, plan)
-            if plan.variable else jnp.float32(plan.bits_per_coord))
+    with jax.named_scope("collective"):
+        gathered = jax.tree.map(transport.all_gather, payload)   # (M, ...)
+    with jax.named_scope("decode"):
+        if plan.integrity:
+            # checked decode: per-(worker, bucket) validity verdicts,
+            # with detected-corrupt buckets excluded from the aggregate
+            # by the per-bucket renormalization rule (a fully-invalid
+            # worker aggregates bit-exactly like a transport-masked
+            # one).  ``own`` comes from the LOCAL payload, not the
+            # gathered row — wire corruption of one's own row must not
+            # poison the error-feedback residual (bit-identical when the
+            # wire is clean).
+            per_worker, valid = codec.decode_checked(
+                gathered, levels, plan, use_pallas=use_pallas)
+            out = transport.mean_workers_bucketed(
+                per_worker, valid, plan.bucket_size)[:d]
+            own = codec.decode(payload, levels, plan,
+                               use_pallas=use_pallas)[:d]
+            corrupt = jnp.mean(1.0 - valid.astype(jnp.float32))
+            excluded = jnp.sum(jnp.all(~valid, axis=1).astype(jnp.float32))
+        else:
+            per_worker = codec.decode(gathered, levels, plan,
+                                      use_pallas=use_pallas)      # (M, n)
+            out = transport.mean_workers(per_worker)[:d]
+            own = jnp.take(per_worker, transport.rank(), axis=0)[:d]
+            corrupt = jnp.float32(0.0)
+            excluded = jnp.float32(0.0)
+    with jax.named_scope("step_metrics"):
+        qerr = jnp.sum((own - flat) ** 2)
+        # the single gather IS the broadcast-all hop (paper Sec. 5);
+        # variable-volume codecs report what their headers say this
+        # worker's payload actually ships, not the static capacity
+        bits = (codec.measured_bits_per_coord(payload, plan)
+                if plan.variable else jnp.float32(plan.bits_per_coord))
     return out, own, SyncMetrics(bits, qerr, jnp.float32(0.0), bits,
                                  corrupt_fraction=corrupt,
                                  excluded_workers=excluded)
@@ -155,67 +166,75 @@ def _allreduce_two_phase(flat, codec, levels, key, transport, use_pallas):
     plan = codec.plan(d, shards=M)
 
     # ---- phase 1: quantized reduce-scatter (scheme grid) ----
-    vb = codec.bucketize(flat, plan)
-    payload = codec.encode(vb, levels, key, plan, use_pallas=use_pallas)
-    if M == 1:  # unsharded payload is 1-D; the wire still sees one row
-        payload = jax.tree.map(lambda a: a[None], payload)
-    received = jax.tree.map(transport.all_to_all, payload)
+    with jax.named_scope("encode"):
+        vb = codec.bucketize(flat, plan)
+        payload = codec.encode(vb, levels, key, plan, use_pallas=use_pallas)
+        if M == 1:  # unsharded payload is 1-D; the wire still sees one row
+            payload = jax.tree.map(lambda a: a[None], payload)
+    with jax.named_scope("collective"):
+        received = jax.tree.map(transport.all_to_all, payload)
     corrupt = jnp.float32(0.0)
     excluded = jnp.float32(0.0)
-    if plan.integrity:
-        shard_per_worker, valid1 = codec.decode_checked(
-            received, levels, plan, shard=transport.rank(),
-            use_pallas=use_pallas)                           # (M, shard_n)
-        shard_mean = transport.mean_workers_bucketed(
-            shard_per_worker, valid1, plan.bucket_size)
-        corrupt = corrupt + jnp.sum(1.0 - valid1.astype(jnp.float32))
-        excluded = jnp.sum(jnp.all(~valid1, axis=1).astype(jnp.float32))
-    else:
-        shard_per_worker = codec.decode(received, levels, plan,
-                                        shard=transport.rank(),
-                                        use_pallas=use_pallas)
-        shard_mean = transport.mean_workers(shard_per_worker)
-    shard_mean = shard_mean.reshape(plan.shard_nb, plan.bucket_size)
+    with jax.named_scope("decode"):
+        if plan.integrity:
+            shard_per_worker, valid1 = codec.decode_checked(
+                received, levels, plan, shard=transport.rank(),
+                use_pallas=use_pallas)                       # (M, shard_n)
+            shard_mean = transport.mean_workers_bucketed(
+                shard_per_worker, valid1, plan.bucket_size)
+            corrupt = corrupt + jnp.sum(1.0 - valid1.astype(jnp.float32))
+            excluded = jnp.sum(jnp.all(~valid1, axis=1).astype(jnp.float32))
+        else:
+            shard_per_worker = codec.decode(received, levels, plan,
+                                            shard=transport.rank(),
+                                            use_pallas=use_pallas)
+            shard_mean = transport.mean_workers(shard_per_worker)
+        shard_mean = shard_mean.reshape(plan.shard_nb, plan.bucket_size)
 
     # ---- phase 2: re-quantize the aggregate, broadcast compressed ----
     codec2 = requant_codec(codec, TWO_PHASE_BITS)
     lv2 = uniform_levels(TWO_PHASE_BITS)
     plan2 = codec2.plan_buckets(plan.shard_nb)
-    pay2 = codec2.encode(shard_mean, lv2,
-                         jax.random.fold_in(key, 0x2FA5E), plan2,
-                         use_pallas=use_pallas)
-    g2 = jax.tree.map(transport.all_gather, pay2)
-    if plan2.integrity:
-        # phase 2 carries each shard of the aggregate exactly once —
-        # no redundancy to renormalize over, so a detected-corrupt
-        # phase-2 bucket zero-fills (skips the coordinate this step)
-        out, valid2 = codec2.decode_checked(g2, lv2, plan2,
-                                            use_pallas=use_pallas)
-        # where, not multiply: corrupt buckets can decode to NaN and
-        # NaN * 0 would leak into the skipped coordinates
-        out = jnp.where(valid2[..., None],
-                        out.reshape(M, plan2.nb, plan2.bucket_size), 0.0)
-        corrupt = corrupt + jnp.sum(1.0 - valid2.astype(jnp.float32))
-        denom = jnp.float32(valid1.size + valid2.size)
-        corrupt = corrupt / denom
-    else:
-        out = codec2.decode(g2, lv2, plan2, use_pallas=use_pallas)
-    # the M shards joined into one materialized stream: a consumer that
-    # reshapes slices of it (the trainer's unravel into parameters)
-    # would otherwise fuse into one relayout of the (M, n) rows per
-    # slice, which took the TPU compiler minutes at 170M coordinates
-    out = jax.lax.optimization_barrier(out.reshape(-1))[:d]
+    with jax.named_scope("encode"):
+        pay2 = codec2.encode(shard_mean, lv2,
+                             jax.random.fold_in(key, 0x2FA5E), plan2,
+                             use_pallas=use_pallas)
+    with jax.named_scope("collective"):
+        g2 = jax.tree.map(transport.all_gather, pay2)
+    with jax.named_scope("decode"):
+        if plan2.integrity:
+            # phase 2 carries each shard of the aggregate exactly once —
+            # no redundancy to renormalize over, so a detected-corrupt
+            # phase-2 bucket zero-fills (skips the coordinate this step)
+            out, valid2 = codec2.decode_checked(g2, lv2, plan2,
+                                                use_pallas=use_pallas)
+            # where, not multiply: corrupt buckets can decode to NaN and
+            # NaN * 0 would leak into the skipped coordinates
+            out = jnp.where(valid2[..., None],
+                            out.reshape(M, plan2.nb, plan2.bucket_size), 0.0)
+            corrupt = corrupt + jnp.sum(1.0 - valid2.astype(jnp.float32))
+            denom = jnp.float32(valid1.size + valid2.size)
+            corrupt = corrupt / denom
+        else:
+            out = codec2.decode(g2, lv2, plan2, use_pallas=use_pallas)
+        # the M shards joined into one materialized stream: a consumer
+        # that reshapes slices of it (the trainer's unravel into
+        # parameters) would otherwise fuse into one relayout of the
+        # (M, n) rows per slice, which took the TPU compiler minutes at
+        # 170M coordinates
+        out = jax.lax.optimization_barrier(out.reshape(-1))[:d]
 
-    # own phase-1 payload, decoded shard by shard, for the error metric
-    # (and for the compress layer's residual feedback)
-    own = codec.decode(payload, levels, plan, shard=None,
-                       use_pallas=use_pallas).reshape(-1)[:d]
-    qerr = jnp.sum((own - flat) ** 2)
-    bits_reduce = (codec.measured_bits_per_coord(payload, plan)
-                   if plan.variable
-                   else jnp.float32(plan.bits_per_coord))
-    bits_bcast = jnp.float32(
-        32.0 * (plan2.code_words + plan2.norm_words) / d)
+        # own phase-1 payload, decoded shard by shard, for the error
+        # metric (and for the compress layer's residual feedback)
+        own = codec.decode(payload, levels, plan, shard=None,
+                           use_pallas=use_pallas).reshape(-1)[:d]
+    with jax.named_scope("step_metrics"):
+        qerr = jnp.sum((own - flat) ** 2)
+        bits_reduce = (codec.measured_bits_per_coord(payload, plan)
+                       if plan.variable
+                       else jnp.float32(plan.bits_per_coord))
+        bits_bcast = jnp.float32(
+            32.0 * (plan2.code_words + plan2.norm_words) / d)
     return out, own, SyncMetrics(bits_reduce + bits_bcast, qerr,
                                  bits_reduce, bits_bcast,
                                  corrupt_fraction=corrupt,
@@ -269,7 +288,8 @@ def quantized_allreduce(
     if transport is None:
         transport = make_transport(axes)
     if mode == "fp32" or not scheme.quantized:
-        out = transport.mean_psum(flat)
+        with jax.named_scope("collective"):
+            out = transport.mean_psum(flat)
         m = SyncMetrics(jnp.float32(32.0), jnp.float32(0.0),
                         jnp.float32(32.0), jnp.float32(0.0),
                         jnp.float32(32.0))
@@ -392,4 +412,5 @@ def maybe_update_levels(
         stats = gather_stats(flat, scheme, axes=axes, use_pallas=use_pallas)
         return scheme.update_state(s, stats)
 
-    return jax.lax.cond(do_update, upd, lambda s: s, state)
+    with jax.named_scope("level_update"):
+        return jax.lax.cond(do_update, upd, lambda s: s, state)

@@ -210,20 +210,30 @@ def run(args, mesh=None) -> RunResult:
         compile_s = time.perf_counter() - t0
         print(f"compiled the step over {tr.mesh.size} device(s) in "
               f"{compile_s:.1f}s", flush=True)
+        # each step's host work in profiler spans, on the device
+        # operations' clock when run under ``jax.profiler.trace``
+        span = jax.profiler.TraceAnnotation
         history, step_s = [], []
         for t in range(start, args.steps):
-            t0 = time.perf_counter()
-            state, metrics = compiled(state, batch)
-            m = {k: float(v) for k, v in jax.device_get(metrics).items()}
-            step_s.append(time.perf_counter() - t0)
-            history.append(m)
-            if t + 1 < args.steps:
-                batch = jax.device_put(tr.pipe.batch(t + 1),
-                                       tr.batch_sharding)
-            if args.ckpt_dir and (
-                    (args.save_every > 0 and (t + 1) % args.save_every == 0)
-                    or t == args.steps - 1):
-                checkpoint.save_step(args.ckpt_dir, t, state)
+            with jax.profiler.StepTraceAnnotation("train", step_num=t):
+                t0 = time.perf_counter()
+                with span("dispatch"):
+                    state, metrics = compiled(state, batch)
+                with span("wait"):
+                    m = {k: float(v)
+                         for k, v in jax.device_get(metrics).items()}
+                step_s.append(time.perf_counter() - t0)
+                history.append(m)
+                if t + 1 < args.steps:
+                    with span("batch"):
+                        batch = jax.device_put(tr.pipe.batch(t + 1),
+                                               tr.batch_sharding)
+                if args.ckpt_dir and (
+                        (args.save_every > 0
+                         and (t + 1) % args.save_every == 0)
+                        or t == args.steps - 1):
+                    with span("checkpoint"):
+                        checkpoint.save_step(args.ckpt_dir, t, state)
             if t % 5 == 0 or t == args.steps - 1:
                 extra = ("" if args.compress == "plain" else
                          f" |e|={m['residual_norm']:.3f}"

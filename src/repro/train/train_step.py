@@ -37,7 +37,6 @@ class SyncMetricsLite(NamedTuple):
     path."""
 
     comm_bits_per_coord: jnp.ndarray
-    quant_error: jnp.ndarray
     reduce_bits_per_coord: jnp.ndarray
     broadcast_bits_per_coord: jnp.ndarray
     entropy_bits_per_coord: jnp.ndarray
@@ -79,10 +78,19 @@ def compress_state_specs(state: TrainState, data_axes=("data",)):
     return CompressState(residual=P(tuple(data_axes)), step=P())
 
 
+# One ``jax.named_scope`` per layer of the data-parallel step, flat
+# (never nested in one another).  Each scope becomes a segment of the
+# ``op_name`` metadata of the compiled step's instructions, so a
+# profile's device ops can be summed by layer; it adds no operation.
+# ``dist/sync.py`` opens the wire's scopes (encode, collective, decode,
+# level_update and its own counters' step_metrics).
+LAYER_SCOPES = ("fwd_bwd", "ravel", "level_update", "encode", "collective",
+                "decode", "optimizer", "step_metrics")
+
 # every scalar train_step emits; launch/dryrun/test harnesses build their
 # shard_map out_specs from this instead of hard-coding the key set
 TRAIN_METRIC_KEYS = (
-    "loss", "grad_norm", "comm_bits_per_coord", "quant_error",
+    "loss", "grad_norm", "comm_bits_per_coord",
     "reduce_bits_per_coord", "broadcast_bits_per_coord",
     "entropy_bits_per_coord", "residual_norm", "kept_fraction",
     "corrupt_fraction", "excluded_workers",
@@ -193,34 +201,35 @@ def make_train_step(model: Model, tcfg: TrainConfig, *, data_axes=("data",)):
         sync_ctx = (state.scheme_state.levels, base_key) if fsdp else None
 
         k = tcfg.microbatches
-        if k <= 1:
-            def loss_fn(p):
-                return model.loss(p, batch, sync_ctx)
+        with jax.named_scope("fwd_bwd"):
+            if k <= 1:
+                def loss_fn(p):
+                    return model.loss(p, batch, sync_ctx)
 
-            loss, grads = jax.value_and_grad(loss_fn)(state.params)
-        else:
-            # gradient accumulation over k micro-batches (scan keeps the
-            # live activation set to one micro-batch)
-            micro = jax.tree.map(
-                lambda a: a.reshape((k, a.shape[0] // k) + a.shape[1:]),
-                batch)
+                loss, grads = jax.value_and_grad(loss_fn)(state.params)
+            else:
+                # gradient accumulation over k micro-batches (scan keeps
+                # the live activation set to one micro-batch)
+                micro = jax.tree.map(
+                    lambda a: a.reshape((k, a.shape[0] // k) + a.shape[1:]),
+                    batch)
 
-            def micro_step(carry, mb):
-                loss_acc, gacc = carry
-                l, g = jax.value_and_grad(
-                    lambda p: model.loss(p, mb, sync_ctx))(state.params)
-                gacc = jax.tree.map(lambda a, b: a + b, gacc, g)
-                return (loss_acc + l, gacc), None
+                def micro_step(carry, mb):
+                    loss_acc, gacc = carry
+                    l, g = jax.value_and_grad(
+                        lambda p: model.loss(p, mb, sync_ctx))(state.params)
+                    gacc = jax.tree.map(lambda a, b: a + b, gacc, g)
+                    return (loss_acc + l, gacc), None
 
-            # accumulate in the parameter dtype (f32 for f32 masters;
-            # bf16 for bf16-param configs like jamba — their grads are
-            # quantized on the wire anyway)
-            zeros = jax.tree.map(
-                lambda a: jnp.zeros(a.shape, a.dtype), state.params)
-            (loss, grads), _ = jax.lax.scan(
-                micro_step, (jnp.zeros((), jnp.float32), zeros), micro)
-            loss = loss / k
-            grads = jax.tree.map(lambda a: a / k, grads)
+                # accumulate in the parameter dtype (f32 for f32 masters;
+                # bf16 for bf16-param configs like jamba — their grads
+                # are quantized on the wire anyway)
+                zeros = jax.tree.map(
+                    lambda a: jnp.zeros(a.shape, a.dtype), state.params)
+                (loss, grads), _ = jax.lax.scan(
+                    micro_step, (jnp.zeros((), jnp.float32), zeros), micro)
+                loss = loss / k
+                grads = jax.tree.map(lambda a: a / k, grads)
 
         new_comp = state.compress_state
         if fsdp:
@@ -254,14 +263,14 @@ def make_train_step(model: Model, tcfg: TrainConfig, *, data_axes=("data",)):
             metrics = SyncMetricsLite(
                 comm_bits_per_coord=jnp.float32(
                     2.0 * wire if quantized_rs else 32.0),
-                quant_error=jnp.float32(0.0),
                 reduce_bits_per_coord=jnp.float32(wire),
                 broadcast_bits_per_coord=jnp.float32(
                     wire if quantized_rs else 0.0),
                 entropy_bits_per_coord=jnp.asarray(
                     scheme_state.entropy_bits, jnp.float32))
         else:
-            flat, unravel = ravel_pytree(grads)
+            with jax.named_scope("ravel"):
+                flat, unravel = ravel_pytree(grads)
             scheme_state = maybe_update_levels(
                 flat, scheme, state.scheme_state,
                 _is_update_step(tcfg, state.step),
@@ -286,38 +295,42 @@ def make_train_step(model: Model, tcfg: TrainConfig, *, data_axes=("data",)):
                         residual=new_comp.residual[None])
                     # per-rank residual magnitudes differ; report the
                     # replicated DP mean
-                    metrics = metrics._replace(
-                        residual_norm=jax.lax.pmean(
-                            jnp.asarray(metrics.residual_norm,
-                                        jnp.float32),
-                            tuple(data_axes)))
-            grads_synced = unravel(synced)
-            grad_norm = jnp.sqrt(jnp.sum(synced * synced))
+                    with jax.named_scope("step_metrics"):
+                        metrics = metrics._replace(
+                            residual_norm=jax.lax.pmean(
+                                jnp.asarray(metrics.residual_norm,
+                                            jnp.float32),
+                                tuple(data_axes)))
+            with jax.named_scope("ravel"):
+                grads_synced = unravel(synced)
+            with jax.named_scope("step_metrics"):
+                grad_norm = jnp.sqrt(jnp.sum(synced * synced))
 
-        new_params, new_opt = apply_updates(
-            tcfg.optim, state.params, grads_synced, state.opt)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = apply_updates(
+                tcfg.optim, state.params, grads_synced, state.opt)
 
         new_state = TrainState(
             params=new_params, opt=new_opt, scheme_state=scheme_state,
             step=state.step + 1, rng=state.rng,
             compress_state=new_comp)
-        out_metrics = {
-            "loss": jax.lax.pmean(loss, tuple(data_axes)),
-            "grad_norm": grad_norm,
-            "comm_bits_per_coord": metrics.comm_bits_per_coord,
-            "quant_error": metrics.quant_error,
-            "reduce_bits_per_coord": metrics.reduce_bits_per_coord,
-            "broadcast_bits_per_coord": metrics.broadcast_bits_per_coord,
-            "entropy_bits_per_coord": metrics.entropy_bits_per_coord,
-            "residual_norm": jnp.asarray(metrics.residual_norm,
-                                         jnp.float32),
-            "kept_fraction": jnp.asarray(metrics.kept_fraction,
-                                         jnp.float32),
-            "corrupt_fraction": jnp.asarray(metrics.corrupt_fraction,
-                                            jnp.float32),
-            "excluded_workers": jnp.asarray(metrics.excluded_workers,
-                                            jnp.float32),
-        }
+        with jax.named_scope("step_metrics"):
+            out_metrics = {
+                "loss": jax.lax.pmean(loss, tuple(data_axes)),
+                "grad_norm": grad_norm,
+                "comm_bits_per_coord": metrics.comm_bits_per_coord,
+                "reduce_bits_per_coord": metrics.reduce_bits_per_coord,
+                "broadcast_bits_per_coord": metrics.broadcast_bits_per_coord,
+                "entropy_bits_per_coord": metrics.entropy_bits_per_coord,
+                "residual_norm": jnp.asarray(metrics.residual_norm,
+                                             jnp.float32),
+                "kept_fraction": jnp.asarray(metrics.kept_fraction,
+                                             jnp.float32),
+                "corrupt_fraction": jnp.asarray(metrics.corrupt_fraction,
+                                                jnp.float32),
+                "excluded_workers": jnp.asarray(metrics.excluded_workers,
+                                                jnp.float32),
+            }
         return new_state, out_metrics
 
     return train_step

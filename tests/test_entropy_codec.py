@@ -366,9 +366,8 @@ def test_metric_defaults_are_float32_scalars():
     for name in ("entropy_bits_per_coord", "residual_norm",
                  "kept_fraction"):
         _assert_f32_scalar(name, getattr(m, name))
-    lite = SyncMetricsLite(jnp.float32(1.0), jnp.float32(0.0),
-                           jnp.float32(1.0), jnp.float32(0.0),
-                           jnp.float32(0.0))
+    lite = SyncMetricsLite(jnp.float32(1.0), jnp.float32(1.0),
+                           jnp.float32(0.0), jnp.float32(0.0))
     for name in ("residual_norm", "kept_fraction"):
         _assert_f32_scalar(name, getattr(lite, name))
     # SchemeState constructed positionally (the benchmark harness path)
