@@ -5,9 +5,14 @@ in [0, 2L-2] and packed ``wire_bits`` per symbol into a dense uint32
 stream.  This is what actually travels over ICI in the quantized
 allreduce: ``ceil(n * wire_bits / 32)`` words instead of n fp32 words.
 
-The packer is fully vectorized (two scatter-adds per stream — one for the
-low fragment of each symbol, one for the fragment spilling into the next
-word), so it lowers cleanly under jit/shard_map on any backend.
+Symbol ``i`` sits at bit ``i * wire_bits`` of the little-endian word
+stream, so the word and the shift of every symbol are static functions
+of its position.  ``unpack`` uses only that: static shifts, masks and
+reshapes, no data-dependent gather.  ``pack`` still scatter-adds twice
+per stream (the low fragment of each symbol, and the fragment spilling
+into the next word).  Both lower under jit/shard_map on any backend, but
+the TPU runs a gather or scatter over every symbol element by element:
+a gather over 452.7M symbols took 10 s on a v5e.
 """
 from __future__ import annotations
 
@@ -56,19 +61,46 @@ def pack(codes: jnp.ndarray, bits: int) -> jnp.ndarray:
     return out[:nwords]
 
 
+def _fit(words: jnp.ndarray, m: int) -> jnp.ndarray:
+    """The first ``m`` words, zero-padded if the stream is shorter."""
+    if words.shape[0] >= m:
+        return words[:m]
+    return jnp.concatenate([words, jnp.zeros((m - words.shape[0],),
+                                             jnp.uint32)])
+
+
 def unpack(words: jnp.ndarray, n: int, bits: int) -> jnp.ndarray:
-    """Inverse of pack: recover n unsigned symbols (int32)."""
-    words = jnp.concatenate(
-        [words.astype(jnp.uint32), jnp.zeros((1,), jnp.uint32)])
-    i = jnp.arange(n, dtype=jnp.uint32)
-    bitpos = i * jnp.uint32(bits)
-    widx = (bitpos >> 5).astype(jnp.int32)
-    off = bitpos & jnp.uint32(31)
-    lo = words[widx] >> off
-    spill_shift = jnp.where(off > 0, jnp.uint32(32) - off, jnp.uint32(31))
-    hi = jnp.where(off > 0, words[widx + 1] << spill_shift, jnp.uint32(0))
+    """Inverse of pack: recover n unsigned symbols (int32).
+
+    Where ``bits`` divides 32 each word holds ``32 // bits`` whole
+    symbols: shift every word by each slot's offset.  Otherwise 32
+    symbols fill exactly ``bits`` words: each of a group's 32 slots comes
+    from a static word and offset, OR-ing in the spill from the next word
+    where the slot crosses one.  Bits past symbol ``n`` are ignored.
+    """
+    words = words.astype(jnp.uint32)
     mask = jnp.uint32((1 << bits) - 1)
-    return ((lo | hi) & mask).astype(jnp.int32)
+    if 32 % bits == 0:
+        per = 32 // bits
+        shifts = jnp.arange(per, dtype=jnp.uint32) * jnp.uint32(bits)
+        sym = _fit(words, -(-n // per))[:, None] >> shifts
+    else:
+        # Rows of 128 groups (4096 symbols), built word-major: word j of
+        # every group is a slice of the major dimension and the slots
+        # stack along it.  A (groups, bits) or (groups, 32) array would
+        # have a narrow minor dimension, which the TPU pads to 128 lanes.
+        rows = -(-n // 4096)
+        v = _fit(words, rows * 128 * bits).reshape(rows, 128 * bits).T
+        v = v.reshape(128, bits, rows)
+        slots = []
+        for k in range(32):
+            wi, off = (k * bits) >> 5, (k * bits) & 31
+            s = v[:, wi] >> off
+            if off + bits > 32:
+                s = s | (v[:, wi + 1] << (32 - off))
+            slots.append(s)
+        sym = jnp.stack(slots, axis=1).reshape(4096, rows).T
+    return (sym & mask).reshape(-1)[:n].astype(jnp.int32)
 
 
 def bias_codes(signed_codes: jnp.ndarray, num_levels: int) -> jnp.ndarray:

@@ -60,6 +60,12 @@ def compiled_text(argv, devices, tmp_path):
         import jax
         from repro.launch import train
         from repro.launch.mesh import make_local_mesh
+        # JAX keeps traced and lowered functions in process-wide caches,
+        # and a lowering can keep the op_names of the trace that made it:
+        # after the simulator's fault scenario, this step's ops read
+        # jit(_threefry_fold_in)/quantized_allreduce/...  Compile from
+        # empty caches, as a process of its own would.
+        jax.clear_caches()
         tr = train.build(train.parse_args(argv),
                          make_local_mesh(devices=jax.devices()[:1]))
         with jax.set_mesh(tr.mesh):

@@ -4,6 +4,9 @@
   lengths — exercising both the word-boundary spill path (bits not
   dividing 32) and the ``off == 0`` masked-shift path (bits dividing 32);
 * packed size is exactly ceil(n*b/32) words;
+* ``unpack`` equals the per-symbol gather it replaced, bit for bit, on
+  random words (garbage past symbol n and in spill positions) at every
+  width 1..16, and lowers with no gather;
 * ``quantized_allreduce(all_gather)`` on the 8-fake-device mesh equals an
   unpacked (codes-never-packed) reference BIT-exactly — the wire really
   carries packed words, and packing is lossless end to end.
@@ -12,6 +15,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -35,6 +39,48 @@ def test_pack_unpack_roundtrip_all_wire_widths(bits, n):
     assert packed.shape[0] == packing.packed_words(n, bits) == -(-n * bits // 32)
     back = packing.unpack(packed, n, bits)
     np.testing.assert_array_equal(np.asarray(back), vals)
+
+
+def _unpack_by_gather(words, n, bits):
+    """The earlier unpack: two gathers of one word per symbol."""
+    words = jnp.concatenate(
+        [words.astype(jnp.uint32), jnp.zeros((1,), jnp.uint32)])
+    bitpos = jnp.arange(n, dtype=jnp.uint32) * jnp.uint32(bits)
+    widx = (bitpos >> 5).astype(jnp.int32)
+    off = bitpos & jnp.uint32(31)
+    lo = words[widx] >> off
+    spill_shift = jnp.where(off > 0, jnp.uint32(32) - off, jnp.uint32(31))
+    hi = jnp.where(off > 0, words[widx + 1] << spill_shift, jnp.uint32(0))
+    return ((lo | hi) & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
+
+
+def _parity_cases():
+    # 4096 symbols are one row of the word-major path (bits not dividing
+    # 32); 8193 spans two rows and one symbol of a third
+    for bits in range(1, 17):
+        per = 32 // bits
+        for n in sorted({1, max(per - 1, 1), per, per + 1, 31, 32, 33, 1000,
+                         4096, 8193}):
+            yield bits, n
+
+
+@pytest.mark.parametrize("bits,n", list(_parity_cases()))
+def test_unpack_matches_gather_on_random_words(bits, n):
+    rng = np.random.default_rng(bits * 7919 + n)
+    words = jnp.asarray(rng.integers(0, 2 ** 32, packing.packed_words(n, bits),
+                                     dtype=np.uint32))
+    got = packing.unpack(words, n, bits)
+    assert got.dtype == jnp.int32 and got.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(_unpack_by_gather(words, n, bits)))
+
+
+@pytest.mark.parametrize("bits", [3, 4, 9])
+def test_unpack_lowers_without_gather(bits):
+    n = 1024 * 64
+    words = jax.ShapeDtypeStruct((packing.packed_words(n, bits),), jnp.uint32)
+    text = jax.jit(lambda w: packing.unpack(w, n, bits)).lower(words).as_text()
+    assert "gather" not in text
 
 
 @pytest.mark.parametrize("num_levels", [2, 8, 16, 128, 256])
