@@ -3,10 +3,11 @@ reference.
 
 Set-up drives the compiled step from the seed through the job's
 ``check_steps`` steps, on the window's own call and a new batch each
-step.  The reference (``bench/reference``: the dense decoder in float32
-at ``highest`` precision, the paper's wire with its own level update and
-its own rounding, AdamW) follows the same steps from the same seeded
-weights and batches.  The numbers compared, each against its limit in
+step.  The reference (``bench/reference``: the architecture module that
+the configuration names, in float32 at ``highest`` precision, the
+paper's wire with its own level update and its own rounding, AdamW)
+follows the same steps from the same seeded weights and batches.  The
+numbers compared, each against its limit in
 ``bench/limits/<cell>.json``:
 
     loss_gap_<t>   |loss_t - reference| / reference, for each step t
@@ -44,8 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
-from bench import generator as gen
-from bench.reference import adamw, dense_decoder, wire as wire_ref
+from bench import generator as gen, reference
+from bench.reference import adamw, wire as wire_ref
 
 # a leaf whose reference gradient norm is under this share of the
 # median leaf's is left out of the leaf comparisons
@@ -83,16 +84,17 @@ class Steps:
     def __init__(self, cfg: dict, wire: dict, job: dict,
                  precision: str = "highest"):
         self.cfg, self.wire, self.job = cfg, wire, job
-        self.init = jax.jit(lambda k: dense_decoder.init_params(cfg, k))
+        arch = reference.of(cfg)
+        self.init = jax.jit(lambda k: arch.init_params(cfg, k))
         self.grad = jax.jit(jax.value_and_grad(
-            lambda p, ids, labels: dense_decoder.loss(cfg, p, ids, labels,
-                                                      precision)))
+            lambda p, ids, labels: arch.loss(cfg, p, ids, labels,
+                                             precision)))
         self.opt = jax.jit(
             lambda p, q, m, v, t: adamw.update(job, p, q, m, v, t),
             donate_argnums=(0, 2, 3))
         self.adam_init = jax.jit(adamw.init)
         self.change = jax.jit(lambda p, k: leaf_norms(
-            jax.tree.map(jnp.subtract, p, dense_decoder.init_params(cfg, k))))
+            jax.tree.map(jnp.subtract, p, arch.init_params(cfg, k))))
         if wire["scheme"] != "fp32":
             self.levels_of = jax.jit(
                 lambda g: wire_ref.adapted_levels(g, wire))
@@ -245,9 +247,9 @@ def verdict(nums: dict, limits: dict) -> bool:
 def leaf_report(cfg: dict, got: Readings, ref: Reference) -> list:
     """One line per leaf: its name and size, the first gradient's and
     the change's norms of ``got`` and ``ref``, and their gaps."""
-    from bench.reference.dense_decoder import param_shapes
     flat = jax.tree_util.tree_flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))[0]
+        reference.of(cfg).param_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple))[0]
     keep = kept(ref)
     g = leaf_gaps(got.first_grad, ref.first_grad, keep)
     c = leaf_gaps(got.change, ref.change, keep)

@@ -19,22 +19,17 @@ them from a seed), with the layers stacked on a leading axis:
                      wo (L, 1, H*hd, d)[, q_norm, k_norm (L, 1, hd)]},
              ffn: {w1, w3 (L, 1, d, F), w2 (L, 1, F, d)}}]
 
-``precision`` is "highest" (float32 matrix products, the reference) or
-"fp8" (each product's operands rounded to float8 e4m3, and the gradient
-flowing back into it to e5m2, each with one scale per tensor, as float8
-training does: the control that a lower-precision path has to fail).
+``precision`` is "highest" (the reference) or "fp8" (the control), as
+``bench.reference.ops.dot`` computes them.  A configuration file names
+this module with ``"reference": "dense_decoder"``; it exports the
+interface that ``bench.reference.of`` looks for.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
-HIGHEST = jax.lax.Precision.HIGHEST
-# largest finite float8 e4m3 and e5m2 values
-_E4M3_MAX = 448.0
-_E5M2_MAX = 57344.0
+from bench.reference.ops import dot, rms, rope
 
 
 def sizes(cfg: dict) -> dict:
@@ -95,96 +90,38 @@ def init_params(cfg: dict, key) -> dict:
     return jax.tree.unflatten(tree, leaves)
 
 
-def _fp8(x, dtype, top):
-    """x rounded to a float8 ``dtype`` under one scale for the tensor."""
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / top
-    return (x / scale).astype(dtype).astype(jnp.float32) * scale
-
-
-def _einsum(spec, a, b):
-    return jnp.einsum(spec, a, b, precision=HIGHEST,
-                      preferred_element_type=jnp.float32)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _fp8_dot(spec, a, b):
-    """A product as float8 training computes it: the operands in e4m3,
-    and in the backward pass the incoming gradient in e5m2."""
-    return _einsum(spec, _fp8(a, jnp.float8_e4m3fn, _E4M3_MAX),
-                   _fp8(b, jnp.float8_e4m3fn, _E4M3_MAX))
-
-
-def _fp8_dot_fwd(spec, a, b):
-    a8 = _fp8(a, jnp.float8_e4m3fn, _E4M3_MAX)
-    b8 = _fp8(b, jnp.float8_e4m3fn, _E4M3_MAX)
-    return _einsum(spec, a8, b8), (a8, b8)
-
-
-def _fp8_dot_bwd(spec, res, g):
-    a8, b8 = res
-    _, pullback = jax.vjp(lambda a, b: _einsum(spec, a, b), a8, b8)
-    return pullback(_fp8(g, jnp.float8_e5m2, _E5M2_MAX))
-
-
-_fp8_dot.defvjp(_fp8_dot_fwd, _fp8_dot_bwd)
-
-
-def _dot(spec: str, a, b, precision: str):
-    if precision == "fp8":
-        return _fp8_dot(spec, a, b)
-    if precision != "highest":
-        raise ValueError(f"unknown precision {precision!r}")
-    return _einsum(spec, a, b)
-
-
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _rope(x, theta):
-    """Rotate the first and second halves of each head (B, S, H, hd)."""
-    S, hd = x.shape[1], x.shape[-1]
-    half = hd // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv[None]
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
 def _block(cfg, s, p, x, precision):
     B, S, d = x.shape
     eps = cfg["rms_norm_eps"]
     res = cfg.get("residual_multiplier", 1.0)
     scale = cfg.get("attention_multiplier", s["hd"] ** -0.5)
-    h = _rms(x, p["norm1"], eps)
+    h = rms(x, p["norm1"], eps)
     m = p["mixer"]
-    q = _dot("bsd,de->bse", h, m["wq"], precision).reshape(
+    q = dot("bsd,de->bse", h, m["wq"], precision).reshape(
         B, S, s["H"], s["hd"])
-    k = _dot("bsd,de->bse", h, m["wk"], precision).reshape(
+    k = dot("bsd,de->bse", h, m["wk"], precision).reshape(
         B, S, s["KV"], s["hd"])
-    v = _dot("bsd,de->bse", h, m["wv"], precision).reshape(
+    v = dot("bsd,de->bse", h, m["wv"], precision).reshape(
         B, S, s["KV"], s["hd"])
     if cfg.get("qk_norm"):
-        q = _rms(q, m["q_norm"], eps)
-        k = _rms(k, m["k_norm"], eps)
-    q = _rope(q, cfg["rope_theta"])
-    k = _rope(k, cfg["rope_theta"])
+        q = rms(q, m["q_norm"], eps)
+        k = rms(k, m["k_norm"], eps)
+    q = rope(q, cfg["rope_theta"])
+    k = rope(k, cfg["rope_theta"])
     rep = s["H"] // s["KV"]
     k = jnp.repeat(k, rep, axis=2)
     v = jnp.repeat(v, rep, axis=2)
-    scores = _dot("bqhd,bkhd->bhqk", q, k, precision) * scale
+    scores = dot("bqhd,bkhd->bhqk", q, k, precision) * scale
     causal = jnp.tril(jnp.ones((S, S), bool))
     scores = jnp.where(causal[None, None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
-    att = _dot("bhqk,bkhd->bqhd", probs, v, precision).reshape(B, S, -1)
-    x = x + res * _dot("bse,ed->bsd", att, m["wo"], precision)
-    h = _rms(x, p["norm2"], eps)
+    att = dot("bhqk,bkhd->bqhd", probs, v, precision).reshape(B, S, -1)
+    x = x + res * dot("bse,ed->bsd", att, m["wo"], precision)
+    h = rms(x, p["norm2"], eps)
     f = p["ffn"]
-    gate = jax.nn.silu(_dot("bsd,df->bsf", h, f["w1"], precision))
-    up = _dot("bsd,df->bsf", h, f["w3"], precision)
-    return x + res * _dot("bsf,fd->bsd", gate * up, f["w2"], precision)
+    gate = jax.nn.silu(dot("bsd,df->bsf", h, f["w1"], precision))
+    up = dot("bsd,df->bsf", h, f["w3"], precision)
+    return x + res * dot("bsf,fd->bsd", gate * up, f["w2"], precision)
 
 
 def loss(cfg: dict, params: dict, ids, labels, precision: str = "highest"):
@@ -198,9 +135,43 @@ def loss(cfg: dict, params: dict, ids, labels, precision: str = "highest"):
             return _block(cfg, s, p, x, precision), None
 
         x, _ = jax.lax.scan(body, x, layers)
-    x = _rms(x, params["final_norm"], cfg["rms_norm_eps"])
-    logits = _dot("bsd,dv->bsv", x, params["lm_head"][0], precision)
+    x = rms(x, params["final_norm"], cfg["rms_norm_eps"])
+    logits = dot("bsd,dv->bsv", x, params["lm_head"][0], precision)
     logits = logits / cfg.get("logits_scaling", 1.0)
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     return jnp.mean(lse - picked)
+
+
+def flops_per_token(cfg: dict, seq_len: int) -> float:
+    """Forward and backward FLOPs per token, without recomputation: 2
+    per weight of every matrix product (LM head included, the embedding
+    lookup not), plus causal attention's two products over (S + 1) / 2
+    positions on average; backward twice forward."""
+    s = sizes(cfg)
+    d, H, KV, hd, F, V, L = (s[k] for k in ("d", "H", "KV", "hd", "F",
+                                            "V", "L"))
+    per_layer = d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * F
+    matmul = L * per_layer + d * V
+    attention = L * 2 * 2 * H * hd * (seq_len + 1) / 2
+    return 3.0 * (2.0 * matmul + attention)
+
+
+def program_checks(cfg: dict, m) -> dict:
+    """{name: (program's value, file's value)} for the program's model
+    configuration ``m``: the sizes and options this module computes
+    with, and that the model is dense (no experts, no other mixer)."""
+    return {
+        "hidden_size": (m.d_model, cfg["hidden_size"]),
+        "intermediate_size": (m.d_ff, cfg["intermediate_size"]),
+        "num_attention_heads": (m.num_heads, cfg["num_attention_heads"]),
+        "num_key_value_heads": (m.num_kv_heads, cfg["num_key_value_heads"]),
+        "head_dim": (m.head_dim_, cfg["head_dim"]),
+        "num_hidden_layers": (m.num_layers, cfg["num_hidden_layers"]),
+        "vocab_size": (m.vocab_size, cfg["vocab_size"]),
+        "rope_theta": (float(m.rope_theta), float(cfg["rope_theta"])),
+        "rms_norm_eps": (m.norm_eps, cfg["rms_norm_eps"]),
+        "qk_norm": (m.qk_norm, cfg["qk_norm"]),
+        "attention_bias": (m.qkv_bias, cfg["attention_bias"]),
+        "dense": (m.arch_type == "dense" and not m.moe, True),
+    }

@@ -5,25 +5,28 @@
 
 One run of one cell of ``BENCHMARK.json``.  It builds the trainer
 through the program's normal path (``repro.launch.train.build``), makes
-the weights on the device from the seed and the token batches with its
-own generator, compiles the cell's one step shape into the persistent
-compilation cache (``<checkout>/.jax_cache`` unless
-``JAX_COMPILATION_CACHE_DIR`` is set), and drives the compiled step
-through the job's checked set-up steps.  It then measures whole steps
-for at least ``--seconds`` (never fewer than one), the window ending
-when the last step's state is ready.
+the weights on the device from the seed with the reference module that
+the configuration names (``bench/reference/<reference>.py``) and the
+token batches with its own generator, compiles the cell's one step
+shape into the persistent compilation cache (``<checkout>/.jax_cache``
+unless ``JAX_COMPILATION_CACHE_DIR`` is set), and drives the compiled
+step through the job's checked set-up steps.  It then measures whole
+steps for at least ``--seconds`` (never fewer than one), the window
+ending when the last step's state is ready.
 
 ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1``
 traces the window with the profiler and reports its per-layer metrics,
 the device's busy time and the breakdown.  Either way, after the window
-the program's state is freed and the plain reference follows the
-checked steps (``bench/check.py``); ``correct`` says whether every
-compared number kept to its limit.  The numbers and limits are the last
-lines of standard error and the last key of the result, which is the
-last line of standard output.
+the program's state is freed and the plain reference, that module among
+it, follows the checked steps (``bench/check.py``); ``correct`` says
+whether every compared number kept to its limit.  The numbers and limits
+are the last lines of standard error and the last key of the result,
+which is the last line of standard output.
 
 Exits non-zero, with no result, where JAX finds no TPU or fewer chips
-than the cell asks for, or where the program is missing.
+than the cell asks for, where the program is missing, or where the
+configuration names no reference module that exists and exports the
+architecture interface (``bench/reference/__init__.py``).
 """
 from __future__ import annotations
 
@@ -92,22 +95,15 @@ def launcher_args(cell) -> list:
 
 def check_program(tr, cell) -> None:
     """The program runs what the configuration, wire and job files
-    state, or the run stops."""
+    state, or the run stops.  The configuration's reference module says
+    which of the model's sizes and options to compare."""
+    from bench import reference
     cfg, wire, job = cell.config, cell.wire, cell.job
     m, o, s = tr.model.cfg, tr.tcfg.optim, tr.tcfg.scheme
-    want = {
-        "hidden_size": (m.d_model, cfg["hidden_size"]),
-        "intermediate_size": (m.d_ff, cfg["intermediate_size"]),
-        "num_attention_heads": (m.num_heads, cfg["num_attention_heads"]),
-        "num_key_value_heads": (m.num_kv_heads, cfg["num_key_value_heads"]),
-        "head_dim": (m.head_dim_, cfg["head_dim"]),
-        "num_hidden_layers": (m.num_layers, cfg["num_hidden_layers"]),
-        "vocab_size": (m.vocab_size, cfg["vocab_size"]),
-        "rope_theta": (float(m.rope_theta), float(cfg["rope_theta"])),
-        "rms_norm_eps": (m.norm_eps, cfg["rms_norm_eps"]),
-        "qk_norm": (m.qk_norm, cfg["qk_norm"]),
-        "attention_bias": (m.qkv_bias, cfg["attention_bias"]),
-        "dense": (m.arch_type == "dense" and not m.moe, True),
+    # the architecture's sizes and options, then what every
+    # configuration, wire and job states
+    want = dict(reference.of(cfg).program_checks(cfg, m))
+    want.update({
         "param_dtype": (m.param_dtype, cfg["param_dtype"]),
         "compute_dtype": (m.compute_dtype, cfg["compute_dtype"]),
         "optimizer": (o.name, job["optimizer"]),
@@ -119,7 +115,7 @@ def check_program(tr, cell) -> None:
         "update_every": (tr.tcfg.update_every, 0),
         "scheme": (s.name, wire["scheme"]),
         "sync": (tr.tcfg.sync_mode, wire["sync"]),
-    }
+    })
     if cell.quantized:
         want.update({
             "bits": (s.bits, wire["bits"]),
@@ -172,10 +168,10 @@ class Run:
         from repro.launch import train
         from repro.launch.mesh import make_local_mesh
         from repro.train.train_step import init_train_state
-        from bench import generator as gen
-        from bench.reference import dense_decoder
+        from bench import generator as gen, reference
 
         cell, seed = self.cell, self.opts.seed
+        arch = reference.of(cell.config)
         mesh = make_local_mesh(devices=self.devices[:cell.chips])
         tr = train.build(train.parse_args(launcher_args(cell)), mesh)
         check_program(tr, cell)
@@ -184,7 +180,7 @@ class Run:
         # the keys are arguments, not constants: one program for every seed
         def make(wkey, rkey):
             state = init_train_state(tr.model, tr.tcfg, wkey)
-            params = dense_decoder.init_params(cell.config, wkey)
+            params = arch.init_params(cell.config, wkey)
             shapes = jax.tree.map(lambda a: (a.shape, a.dtype), params)
             theirs = jax.tree.map(lambda a: (a.shape, a.dtype), state.params)
             if shapes != theirs:
@@ -322,16 +318,17 @@ class Run:
             import jax
             import jax.numpy as jnp
             from jax.flatten_util import ravel_pytree
-            from bench import generator as gen
-            from bench.reference import dense_decoder, wire as wire_ref
+            from bench import generator as gen, reference
+            from bench.reference import wire as wire_ref
             cfg, wire = self.cell.config, self.cell.wire
+            arch = reference.of(cfg)
             b = gen.batch(self.cell.job, cfg["vocab_size"], self.opts.seed,
                           self.last_step)
 
             @jax.jit
             def nvar(params, ids, labels, levels):
-                g = jax.grad(lambda p: dense_decoder.loss(
-                    cfg, p, ids, labels))(params)
+                g = jax.grad(lambda p: arch.loss(
+                    cfg, p, ids, labels, "highest"))(params)
                 flat = ravel_pytree(g)[0]
                 return (wire_ref.rounding_variance(flat, levels,
                                                    wire["bucket"])

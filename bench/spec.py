@@ -4,6 +4,9 @@ A cell names a configuration and a traffic mix.  The traffic file names
 a wire and a job.  Each lives in a file of its own:
 
     bench/configs/<config>.json    sizes of the model as run, its source
+    bench/reference/<reference>.py the plain reference of the model's
+                                   architecture, which the configuration
+                                   names (``bench.reference.of``)
     bench/traffic/<traffic>.json   {"wire": <wire>, "job": <job>}
     bench/wires/<wire>.json        quantization scheme and sync mode
     bench/jobs/<job>.json          batch, tokens, optimizer, schedule
@@ -11,7 +14,8 @@ a wire and a job.  Each lives in a file of its own:
                                    compares
     bench/metrics/<metric>.py      the reader of one per-layer metric
 
-so that a later cell, wire, job or metric is new files and new entries.
+so that a later architecture, cell, wire, job or metric is new files
+and new entries.
 """
 from __future__ import annotations
 

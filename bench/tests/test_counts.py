@@ -4,7 +4,7 @@ import os
 
 import pytest
 
-from conftest import ROOT
+from tiny import ROOT
 
 from bench import yardstick
 

@@ -8,7 +8,7 @@ import sys
 import pytest
 
 import tiny
-from conftest import ROOT
+from tiny import ROOT
 
 from bench import run
 from repro.models.transformer import Model

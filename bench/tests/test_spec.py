@@ -6,7 +6,7 @@ import re
 
 import pytest
 
-from conftest import ROOT
+from tiny import ROOT
 
 from bench.spec import NAME, UNIT, Spec
 

@@ -9,7 +9,9 @@ from __future__ import annotations
 
 import math
 
-from bench.reference.dense_decoder import param_shapes, sizes
+import jax
+
+from bench import reference
 
 PEAKS = {
     "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9},
@@ -26,27 +28,15 @@ def peaks(device_kind: str) -> dict:
 
 def num_coords(cfg: dict) -> int:
     """Gradient coordinates: every weight of the model."""
-    shapes = param_shapes(cfg)
-    leaves = [shapes["embed"], shapes["lm_head"], shapes["final_norm"]]
-    for slot in shapes["slots"]:
-        leaves += [slot["norm1"], slot["norm2"], *slot["mixer"].values(),
-                   *slot["ffn"].values()]
-    return sum(math.prod(s) for s in leaves)
+    shapes = reference.of(cfg).param_shapes(cfg)
+    return sum(math.prod(s) for s in jax.tree.leaves(
+        shapes, is_leaf=lambda x: isinstance(x, tuple)))
 
 
 def model_flops_per_token(cfg: dict, seq_len: int) -> float:
-    """Forward and backward FLOPs per token of a dense decoder, without
-    recomputation: 2 per weight of every matrix product (LM head
-    included, the embedding lookup not), plus causal attention's two
-    products over (S + 1) / 2 positions on average; backward twice
-    forward."""
-    s = sizes(cfg)
-    d, H, KV, hd, F, V, L = (s[k] for k in ("d", "H", "KV", "hd", "F",
-                                            "V", "L"))
-    per_layer = d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * F
-    matmul = L * per_layer + d * V
-    attention = L * 2 * 2 * H * hd * (seq_len + 1) / 2
-    return 3.0 * (2.0 * matmul + attention)
+    """Forward and backward FLOPs per token of the configuration's
+    architecture, without recomputation."""
+    return reference.of(cfg).flops_per_token(cfg, seq_len)
 
 
 # per coordinate: bytes moved and vector operations, for 2**bits levels
